@@ -83,7 +83,7 @@ class Config:
         return mesh.make_mesh(self.mesh_shape, devices=devices)
 
 
-# The conformance anchor config: BASELINE.md config 1 (AWSet 3 replicas x 16
+# The conformance anchor config: BASELINE.json config 1 (AWSet 3 replicas x 16
 # elements, go-test-equivalent semantics).  Each replica is its own actor
 # (awset_test.go:159-168 gives actor i to replica i), so the actor axis must
 # cover the replica count.

@@ -101,7 +101,7 @@ class PeerProtocolError(SyncError, ProtocolError):
 
 class SyncStats(NamedTuple):
     """One push-pull exchange, measured (δ-payload-bytes is a north-star
-    metric, BASELINE.md)."""
+    metric, BASELINE.json)."""
 
     bytes_sent: int
     bytes_received: int

@@ -1,4 +1,4 @@
-"""North-star metrics plumbing (SURVEY §5.5, BASELINE.md).
+"""North-star metrics plumbing (SURVEY §5.5, BASELINE.json).
 
 The reference has zero metrics machinery; its operational counters are
 implicit in stdout traces.  This module gives the framework the three

@@ -42,10 +42,13 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from go_crdt_playground_tpu.models.awset_delta import AWSetDeltaState
 from go_crdt_playground_tpu.ops.pallas_merge import (_LANE, _round_up,
                                                      gather_rows)
+
+_SUB = 8  # sublanes of one vreg tile
 
 
 def _ingest_kernel(actor_ref, vv_ref, p_ref, da_ref, dc_ref, d_ref,
@@ -56,23 +59,23 @@ def _ingest_kernel(actor_ref, vv_ref, p_ref, da_ref, dc_ref, d_ref,
     """One element block: fold all B rows over the resident lanes, then
     extract the block's δ sections vs the PRE-batch vv.  Masks ride as
     uint8 (select between i1 vectors doesn't lower on Mosaic)."""
-    actor = actor_ref[...]            # uint32[1, 1]
+    actor = actor_ref[0]              # uint32 scalar (SMEM)
     num_rows = arow_ref.shape[0]
 
     def body(b, carry):
         p, da, dc, d, dda, ddc = carry
-        on = arow_ref[pl.ds(b, 1), :] != 0           # uint32 row -> mask
-        adc = adddc_ref[pl.ds(b, 1), :]
+        on = arow_ref[b] != 0                        # uint32 row -> mask
+        adc = adddc_ref[b]
         p = jnp.where(on, jnp.uint8(1), p)
         da = jnp.where(on, actor, da)
         dc = jnp.where(on, adc, dc)
-        hit = (drow_ref[pl.ds(b, 1), :] != 0) & (p != 0)
+        hit = (drow_ref[b] != 0) & (p != 0)
         p = jnp.where(hit, jnp.uint8(0), p)
         da = jnp.where(hit, jnp.uint32(0), da)
         dc = jnp.where(hit, jnp.uint32(0), dc)
         d = jnp.where(hit, jnp.uint8(1), d)
         dda = jnp.where(hit, actor, dda)
-        ddc = jnp.where(hit, delctr_ref[pl.ds(b, 1), :], ddc)
+        ddc = jnp.where(hit, delctr_ref[b], ddc)
         return p, da, dc, d, dda, ddc
 
     p, da, dc, d, dda, ddc = jax.lax.fori_loop(
@@ -110,12 +113,16 @@ def _fused_ingest(state: AWSetDeltaState, add_rows, del_rows, live,
 
     num_b, num_e = add_rows.shape
     num_a = state.vv.shape[0]
-    e_pad = _round_up(num_e, _LANE)
+    # The E lanes ride as _SUB sublanes x cols: Mosaic's lane gather
+    # (gather_rows) only lowers on full (8, 128) tiles, and a 1-row
+    # (1, E) layout is refused.  Lane e sits at (e // cols, e % cols),
+    # so a plain reshape maps it back.
+    e_pad = _round_up(num_e, _SUB * _LANE)
+    cols = e_pad // _SUB
     a_pad = _round_up(num_a, _LANE)
-    blk = min(_round_up(block_e, _LANE), e_pad)
-    while e_pad % blk:
+    blk = min(_round_up(max(block_e // _SUB, 1), _LANE), cols)
+    while cols % blk:
         blk -= _LANE
-    b_pad = _round_up(max(num_b, 8), 8)
 
     a = state.actor.astype(jnp.int32)
     pre_vv = state.vv
@@ -132,46 +139,48 @@ def _fused_ingest(state: AWSetDeltaState, add_rows, del_rows, live,
     new_vv = pre_vv.at[a].set(final)
     new_processed = state.processed.at[a].set(final)
 
-    def pad_rows(x):
-        return jnp.pad(x, ((0, b_pad - num_b), (0, e_pad - num_e)))
+    def tile_rows(x):
+        x = jnp.pad(x, ((0, 0), (0, e_pad - num_e)))
+        return x.reshape(num_b, _SUB, cols)
 
-    def pad_lane(x, width):
+    def tile_lane(x):
         x = x.astype(jnp.uint8) if x.dtype == jnp.bool_ else x
-        return jnp.pad(x[None, :], ((0, 0), (0, width - x.shape[0])))
+        return jnp.pad(x, (0, e_pad - num_e)).reshape(_SUB, cols)
 
     ins = [
-        state.actor.astype(jnp.uint32).reshape(1, 1),
-        pad_lane(pre_vv, a_pad),
-        pad_lane(state.present, e_pad),
-        pad_lane(state.dot_actor, e_pad),
-        pad_lane(state.dot_counter, e_pad),
-        pad_lane(state.deleted, e_pad),
-        pad_lane(state.del_dot_actor, e_pad),
-        pad_lane(state.del_dot_counter, e_pad),
-        pad_rows(arow),
-        pad_rows(drow),
-        pad_rows(add_dc),
-        jnp.pad(del_ctr[:, None], ((0, b_pad - num_b), (0, 0))),
+        state.actor.astype(jnp.uint32).reshape(1),
+        jnp.broadcast_to(jnp.pad(pre_vv, (0, a_pad - num_a)),
+                         (_SUB, a_pad)),
+        tile_lane(state.present),
+        tile_lane(state.dot_actor),
+        tile_lane(state.dot_counter),
+        tile_lane(state.deleted),
+        tile_lane(state.del_dot_actor),
+        tile_lane(state.del_dot_counter),
+        tile_rows(arow),
+        tile_rows(drow),
+        tile_rows(add_dc),
+        del_ctr,
     ]
-    one = pl.BlockSpec((1, 1), lambda j: (0, 0))
-    a_blk = pl.BlockSpec((1, a_pad), lambda j: (0, 0))
-    e_blk = pl.BlockSpec((1, blk), lambda j: (0, j))
-    r_blk = pl.BlockSpec((b_pad, blk), lambda j: (0, j))
-    c_blk = pl.BlockSpec((b_pad, 1), lambda j: (0, 0))
-    in_specs = [one, a_blk, e_blk, e_blk, e_blk, e_blk, e_blk, e_blk,
-                r_blk, r_blk, r_blk, c_blk]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    a_blk = pl.BlockSpec((_SUB, a_pad), lambda j: (0, 0))
+    e_blk = pl.BlockSpec((_SUB, blk), lambda j: (0, j))
+    r_blk = pl.BlockSpec((num_b, _SUB, blk), lambda j: (0, 0, j))
+    in_specs = [smem, a_blk, e_blk, e_blk, e_blk, e_blk, e_blk, e_blk,
+                r_blk, r_blk, r_blk, smem]
     u8, u32 = jnp.uint8, jnp.uint32
     out_dts = [u8, u32, u32, u8, u32, u32, u8, u32, u32, u8, u32, u32]
     outs = pl.pallas_call(
         _ingest_kernel,
-        grid=(e_pad // blk,),
+        grid=(cols // blk,),
         in_specs=in_specs,
         out_specs=[e_blk] * 12,
-        out_shape=[jax.ShapeDtypeStruct((1, e_pad), d) for d in out_dts],
+        out_shape=[jax.ShapeDtypeStruct((_SUB, cols), d) for d in out_dts],
         interpret=interpret,
     )(*ins)
     (p, da, dc, d, dda, ddc,
-     ch, chda, chdc, dm, dlda, dldc) = (o[0, :num_e] for o in outs)
+     ch, chda, chdc, dm, dlda, dldc) = (o.reshape(e_pad)[:num_e]
+                                        for o in outs)
 
     merged = AWSetDeltaState(
         vv=new_vv, present=p != 0, dot_actor=da, dot_counter=dc,

@@ -244,7 +244,7 @@ def decode_payload(buf: bytes, num_elements: int, num_actors: int,
 
 def payload_nbytes_wire(p: DeltaPayload) -> int:
     """Wire size of a payload — the honest δ-payload-bytes metric
-    (BASELINE.md north-star metrics) as shipped, vs nbytes_dense for the
+    (BASELINE.json north-star metrics) as shipped, vs nbytes_dense for the
     on-device dense form."""
     return len(encode_payload(p))
 

@@ -209,7 +209,7 @@ def test_gc_participation_mask_blocks_frontier():
 
 def test_add_elements_batch_matches_sequential_adds():
     """add_elements (one fused dispatch per Add(k...) call, the add-path
-    analogue of the del_elements selector — VERDICT r1 #8) must be
+    analogue of the del_elements selector) must be
     bitwise the per-key add_element loop, including the duplicate-key
     case where the loop's later tick overwrites the earlier dot."""
     def seed(st):

@@ -1,6 +1,6 @@
 """Host-fleet soak: N node PROCESSES gossiping over real TCP through
-lossy proxies (VERDICT r4 item #7 — awset_test.go:16-17's exchange
-model made real at fleet scale).
+lossy proxies (awset_test.go:16-17's exchange model made real at
+fleet scale).
 
 The parent runs one lossy TCP proxy per worker: a seeded 20% of proxied
 connections are CUT after forwarding a random prefix (torn frames /

@@ -152,3 +152,26 @@ def test_pallas_twin_covers_uncovered_preexisting_lanes():
         _, got, _ = fn(row, jnp.asarray(add), jnp.asarray(dl),
                        jnp.asarray(live), k_changed=16, k_deleted=16)
         _assert_trees_equal(got, want, impl)
+
+
+@pytest.mark.parametrize("num_e", [1024, 5000])
+def test_pallas_twin_tiles_lanes_over_sublanes_and_blocks(num_e):
+    """The kernel lays the E lanes out as 8 sublanes x E/8 (the layout
+    Mosaic's lane gather lowers on) and walks several element blocks
+    past 1024 lanes: padding, the lane-to-tile map and the block index
+    maps must keep it bitwise the XLA fused path."""
+    rng = np.random.default_rng(num_e)
+    st = awset_delta.init(1, num_e, A, actors=np.asarray([2], np.uint32))
+    row = ingest_ops.ingest_rows(
+        jax.tree.map(lambda x: x[0], st),
+        jnp.asarray(rng.random((3, num_e)) < 0.2),
+        jnp.asarray(rng.random((3, num_e)) < 0.1), jnp.ones(3, bool))
+    add = jnp.asarray(rng.random((8, num_e)) < 0.05)
+    dl = jnp.asarray(rng.random((8, num_e)) < 0.05)
+    live = jnp.asarray(np.arange(8) % 3 != 1)
+    want = ingest_ops.ingest_rows_delta(row, add, dl, live,
+                                        k_changed=64, k_deleted=64)
+    got = pallas_ingest_rows_delta(row, add, dl, live, k_changed=64,
+                                   k_deleted=64, block_e=1024)
+    for w, g, label in zip(want, got, ("state", "payload", "compact")):
+        _assert_trees_equal(g, w, label)

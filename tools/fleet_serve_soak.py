@@ -755,8 +755,7 @@ def mesh_sweep_leg(root: str, devices, elements: int, rate: float,
     the 1-D CURVE records the mesh path's goodput/p99 per width
     (regime documentation); the 2-D dp ladder DOES make a scaling
     claim even here — dp multiplies the rows per dispatch+fsync, which
-    is dispatch-count amortization, not core parallelism.  The on-chip
-    capture rides tools/capture_all.sh.
+    is dispatch-count amortization, not core parallelism.
 
     ``keys`` forwards a named key picker (tools/workloads.py — the
     zipf mode's hot-key streams), ``sched`` the worker's ``--sched``
