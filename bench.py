@@ -1138,7 +1138,7 @@ def measure_ingest(num_elements=1024, num_actors=8,
                 row, addj, dlj, livej, k_changed=k, k_deleted=k)
             jax.block_until_ready(payload if compact is None else compact)
             body, _ = framing.encode_delta_wal_record(
-                pre_vv, 0, payload, compact)
+                pre_vv, 0, payload, jax.device_get(compact))
             return len(body)
 
         def timed(fn):

@@ -5,8 +5,10 @@ Three name sets, all derived mechanically:
 
 * **emitted** — every literal (or f-string pattern) passed to an
   ``obs.Recorder`` emission call in the package: ``count`` /
-  ``count_many`` (dict-literal keys) / ``observe`` / ``set_gauge``,
-  plus the ``_count`` wrapper convention every subsystem uses.
+  ``count_many`` (dict-literal keys) / ``observe`` /
+  ``observe_deferred`` / ``set_gauge``, the spans ``time`` and
+  ``obs.timed``, plus the ``_count`` wrapper convention every
+  subsystem uses.
   F-string segments become ``*`` wildcards (``sync.failures.{cls}`` →
   ``sync.failures.*``), so classified counters stay checkable.
 * **referenced** — dotted metric-shaped string literals in
@@ -49,7 +51,8 @@ _NAME_RE = re.compile(r"^([a-z][a-z0-9_]*|\*)(\.[a-z0-9_*:]+)+\*?$")
 _NOT_METRIC_RE = re.compile(
     r"\.(json|jsonl|py|sh|log|md|txt|ckpt|tmp|wal|proto|cpp|go|toml)$|/")
 
-_EMIT_METHODS = {"count", "observe", "set_gauge", "_count"}
+_EMIT_METHODS = {"count", "observe", "observe_deferred", "set_gauge",
+                 "_count", "time"}
 
 
 def _patterns_of(node: ast.AST) -> List[str]:
@@ -117,6 +120,8 @@ def emitted_patterns(paths: Iterable[str],
                 record(_patterns_of(node.args[0]), path, node.lineno)
             elif fname == "count_many" and node.args:
                 record(_patterns_of(node.args[0]), path, node.lineno)
+            elif fname == "timed" and len(node.args) > 1:
+                record(_patterns_of(node.args[1]), path, node.lineno)
     return out
 
 

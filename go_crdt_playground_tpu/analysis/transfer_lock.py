@@ -19,9 +19,8 @@ annotation stating why it is one sanctioned bounded pull.
    caller holds the lock for the whole body);
 3. anywhere in a function REACHABLE from (1) or (2) through the swept
    files' call graph, matched by trailing callee name (one fixpoint —
-   how ``framing.encode_delta_wal_record``'s single compact pull,
-   called under the node lock from ``Node._append_delta_record``, is
-   found in a different module from any ``with`` block).
+   how a pull in a helper of another module, called under the node
+   lock, is found far from any ``with`` block).
 
 The trailing-name propagation over-approximates (any same-named
 function anywhere in the sweep joins the lock context), which is the

@@ -51,6 +51,7 @@ from go_crdt_playground_tpu.net import framing
 from go_crdt_playground_tpu.net.peer import (ConnectFailed, Node,
                                              PeerProtocolError, PeerReset,
                                              PeerTimeout)
+from go_crdt_playground_tpu.obs import timed
 from go_crdt_playground_tpu.utils.backoff import Backoff, BackoffPolicy
 
 Addr = Tuple[str, int]
@@ -624,12 +625,15 @@ class SyncSupervisor:
         next verified generation AND truncates the WAL under one node
         lock hold (the truncated records are exactly the ones the dump
         contains); without it, the legacy single-file ``Node.save``.
-        Returns the written path."""
+        Returns the written path.  The dump is the span
+        ``node.checkpoint_s``."""
         with self._lock:
             meta = {"supervisor_rounds": self._rounds_done}
         with self._ckpt_lock:
             if self._store is not None:
-                gen = self.node.save_durable(self._store, metadata=meta)
+                with timed(self.recorder, "node.checkpoint_s"):
+                    gen = self.node.save_durable(self._store,
+                                                 metadata=meta)
                 self._count("sync.checkpoints")
                 return self._store.path_for(gen)
             if not self.checkpoint_path:

@@ -254,8 +254,9 @@ def encode_delta_wal_record(pre_vv: np.ndarray, src_actor: int, payload,
     never measure a policy the server no longer runs.
 
     Selection ladder (DESIGN.md §16): the fixed-K on-device form when
-    ``compact`` (an ``ops/compact.CompactDeltaPayload``) is given and
-    did not overflow → host-side compaction of the dense ``payload``
+    ``compact`` (an ``ops/compact.CompactDeltaPayload``, already pulled
+    to the host: ``Node._append_delta_record`` times that pull) is
+    given and did not overflow → host-side compaction of the dense ``payload``
     while under the break-even (~3 bytes of index varints per claimed
     lane vs the dense record's two E/8-byte section bitmasks) → the
     legacy dense record (guard-vv || PAYLOAD body).  Nothing is ever
@@ -292,16 +293,6 @@ def encode_delta_wal_record(pre_vv: np.ndarray, src_actor: int, payload,
         return dc > np.take(pre_vv, da.astype(np.int64), mode="clip")
 
     if compact_records:
-        if compact is not None:
-            import jax
-
-            # one pull for the whole fixed-K pytree — device_get starts
-            # every leaf's transfer before blocking, vs a sequential
-            # device round-trip per field under the node lock
-            # transfer-ok: the one sanctioned bounded pull of the WAL
-            # encode path (called under the node lock via
-            # _append_delta_record)
-            compact = jax.device_get(compact)
         if compact is not None and not bool(compact.overflow):
             chv = compact.ch_valid
             dlv = compact.del_valid & np.asarray(

@@ -64,6 +64,7 @@ from go_crdt_playground_tpu.net import framing
 from go_crdt_playground_tpu.net.framing import (MODE_DELTA, MODE_FULL,
                                                 MODE_SLICE, MSG_HELLO,
                                                 MSG_PAYLOAD, ProtocolError)
+from go_crdt_playground_tpu.obs import timed
 
 
 class SyncError(Exception):
@@ -168,6 +169,9 @@ class Node:
         # (fused_fn, k) resolved on first fused batch — backend and E
         # are fixed for the node's lifetime
         self._fused_regime = None  # guarded-by: _lock
+        # the open node.ingest.dispatch_s span of the batch being
+        # applied; _append_delta_record ends it where the record begins
+        self._dispatch_span = None  # guarded-by: _lock
         # digest-sync kernel dispatch (net/digestsync.py), resolved on
         # first digest exchange — backend and E are lifetime-fixed
         # race-ok: idempotent lazy init (every racer computes the same
@@ -295,6 +299,13 @@ class Node:
         compiled applies per batch (fused: 1; seed path: 2 when a WAL
         is attached).
 
+        Spans (``Recorder.time``): ``node.ingest.dispatch_s`` from
+        before the node lock (its wait, the pre-vv pull, the row
+        slice, the H2D of the rows, the kernel enqueue and the
+        write-back) to where the δ record begins; the record's own
+        spans follow (``_append_delta_record``), so none encloses
+        another.
+
         ``stripe_hint`` is the conflict-aware admission scheduler's
         per-row stripe assignment (serve/scheduler.py; int per batch
         row, negatives = unhinted).  Only a target with replicated
@@ -320,11 +331,17 @@ class Node:
                 raise ValueError(
                     f"stripe hint shape {stripe_hint.shape} does not "
                     f"match batch axis {add_rows.shape[0]}")
-        with self._lock:
-            pre_vv = (np.asarray(self._state.vv[0]).copy()
-                      if self.wal is not None else None)
-            self._apply_batch_locked(add_rows, del_rows, live, pre_vv,
-                                     stripe_hint=stripe_hint)
+        with timed(self.recorder, "node.ingest.dispatch_s") as dispatch:
+            with self._lock:
+                self._dispatch_span = dispatch
+                try:
+                    pre_vv = (np.asarray(self._state.vv[0]).copy()
+                              if self.wal is not None else None)
+                    self._apply_batch_locked(add_rows, del_rows, live,
+                                             pre_vv,
+                                             stripe_hint=stripe_hint)
+                finally:
+                    self._dispatch_span = None
 
     # requires-lock: _lock
     def _apply_batch_locked(self, add_rows: np.ndarray,
@@ -353,6 +370,9 @@ class Node:
             if self._fused_regime is None:
                 self._fused_regime = ingest_ops.ingest_delta_regime(
                     self.num_elements)
+                # at 0 from here on: a reader tells "never overflowed"
+                # from a node that does not count
+                self._count("ingest.compact_overflow", 0)
             fused_fn, k = self._fused_regime
             merged, payload, compact = fused_fn(
                 row, jnp.asarray(add_rows), jnp.asarray(del_rows),
@@ -559,10 +579,41 @@ class Node:
         from the dense payload under the break-even rule, and an
         oversized δ falls back to the dense record — O(E) bytes for
         that batch, nothing is ever dropped.  Caller holds the
-        lock."""
-        body, is_compact = framing.encode_delta_wal_record(
-            pre_vv, self.actor, payload, compact,
-            compact_records=self.wal_compact_records)
+        lock.
+
+        Spans: the batch's dispatch span ends here;
+        ``wal.delta.pull_s`` is the blocking pull of the compact form,
+        where the device's kernel and compaction time surfaces on the
+        host; ``wal.delta.dense_s`` builds the record from the dense δ
+        (its pull, host compaction, deletion filter) when the compact
+        form is absent or overflowed — counted per batch as
+        ``ingest.compact_overflow``; the WAL times its own append
+        (``wal.fsync_s``)."""
+        if self._dispatch_span is not None:
+            self._dispatch_span.end()
+        if not self.wal_compact_records:
+            compact = None
+        if compact is not None:
+            import jax
+
+            with timed(self.recorder, "wal.delta.pull_s"):
+                # one pull for the whole fixed-K pytree — device_get
+                # starts every leaf's transfer before blocking, vs a
+                # sequential device round-trip per field under the lock
+                # transfer-ok: the one sanctioned bounded pull of the
+                # WAL encode path (caller holds the node lock)
+                compact = jax.device_get(compact)
+            if bool(compact.overflow):
+                self._count("ingest.compact_overflow")
+                compact = None
+        if compact is None:
+            with timed(self.recorder, "wal.delta.dense_s"):
+                body, is_compact = framing.encode_delta_wal_record(
+                    pre_vv, self.actor, payload,
+                    compact_records=self.wal_compact_records)
+        else:
+            body, is_compact = framing.encode_delta_wal_record(
+                pre_vv, self.actor, payload, compact)
         self.wal.append(body)
         self._count("wal.compact_records" if is_compact
                     else "wal.dense_records")

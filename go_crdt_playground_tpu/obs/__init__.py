@@ -10,7 +10,8 @@ the north-star counters (merges/sec, rounds-to-convergence, δ-payload
 bytes; SURVEY §5.5).
 """
 
-from go_crdt_playground_tpu.obs.metrics import Recorder, payload_metrics  # noqa: F401
+from go_crdt_playground_tpu.obs.metrics import (  # noqa: F401
+    Recorder, payload_metrics, timed, watch_gc)
 
 # trace.py pulls in ops.merge -> jax; keep the metrics-only import path
 # (net.Node defers jax the same way) light by lazy-loading the renderers.
@@ -20,7 +21,8 @@ _TRACE_EXPORTS = frozenset({
     "format_delta_extract", "format_delta_extract_tensor",
 })
 
-__all__ = ["Recorder", "payload_metrics", *sorted(_TRACE_EXPORTS)]
+__all__ = ["Recorder", "payload_metrics", "timed", "watch_gc",
+           *sorted(_TRACE_EXPORTS)]
 
 
 def __getattr__(name: str):

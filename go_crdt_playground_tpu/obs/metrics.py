@@ -6,14 +6,26 @@ counters the measurement ladder tracks — merges/sec, rounds-to-
 convergence, δ-payload bytes — behind one small thread-safe ``Recorder``
 (net.Node takes one and counts every sync exchange on it) plus
 payload-size helpers for δ payloads.
+
+Spans: ``Recorder.time(name)`` is the program's one span API.  A span
+is an observation in seconds on the Recorder AND, while a
+``jax.profiler`` session runs, a host event of the same name on the
+profiler's timeline, beside the device's operations.  Spans on one
+thread never enclose one another (DESIGN.md §16 "Host spans"), so the
+host event overlapping a device-idle gap names one phase.  JAX is never
+imported here: a process that has not loaded it has no profiler
+session to write to.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import gc
 import math
+import sys
 import threading
 import time
-from contextlib import contextmanager
 from typing import Dict, List, Optional, Sequence
 
 # Bounded log-spaced histogram backing observe()/percentile().  Bucket i
@@ -40,6 +52,58 @@ def _bucket_upper(index: int) -> float:
     return _HIST_BASE * (_HIST_GROWTH ** index)
 
 
+_NO_EVENT = contextlib.nullcontext()
+
+
+def _host_event(name: str):
+    """``jax.profiler.TraceAnnotation(name)`` if this process has
+    loaded JAX (with no session active it costs one flag check), else a
+    no-op."""
+    prof = sys.modules.get("jax.profiler")
+    annotation = getattr(prof, "TraceAnnotation", None)
+    return _NO_EVENT if annotation is None else annotation(name)
+
+
+class Span:
+    """One timed phase: ``with recorder.time(name) as span``.  Observes
+    the elapsed seconds under ``name`` and brackets the same interval
+    with a profiler host event.  ``end()`` closes it early (idempotent;
+    the ``with`` exit then does nothing) — how a phase hands over to
+    the next one inside a block it does not own."""
+
+    __slots__ = ("_recorder", "_name", "_event", "_t0")
+
+    def __init__(self, recorder: Optional["Recorder"], name: str):
+        self._recorder = recorder
+        self._name = name
+        self._event = None
+        self._t0: Optional[float] = None
+
+    def __enter__(self) -> "Span":
+        if self._recorder is not None:
+            self._event = _host_event(self._name)
+            self._event.__enter__()
+            self._t0 = time.perf_counter()
+        return self
+
+    def end(self) -> None:
+        if self._t0 is None:
+            return
+        elapsed = time.perf_counter() - self._t0
+        self._t0 = None
+        self._event.__exit__(None, None, None)
+        self._recorder.observe(self._name, elapsed)
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
+def timed(recorder: Optional["Recorder"], name: str) -> Span:
+    """``recorder.time(name)`` for a component whose recorder is
+    optional: with none, the span records nothing."""
+    return Span(recorder, name)
+
+
 class Recorder:
     """Thread-safe counters, value observations, and wall-clock timers.
 
@@ -51,7 +115,12 @@ class Recorder:
                   relative error, clamped to the exact observed min/max);
                   snapshot() reports p50/p95/p99 per stream — the serve
                   frontend's SLO numbers (DESIGN.md §16) ride these.
-    time():       context manager feeding observe() with elapsed seconds.
+    time():       a ``Span``: observe() fed with elapsed seconds, and a
+                  profiler host event of the same name (module doc).
+    observe_deferred(): observe() for code that may run while this
+                  thread already holds the lock (a ``gc.callbacks``
+                  hook — ``watch_gc``): queued without locking, folded
+                  in by the next locked call.
     set_gauge():  last-write-wins point-in-time values (e.g. the per-peer
                   circuit-breaker state the sync supervisor exports:
                   0=closed, 1=open, 2=half_open — net/antientropy.py).
@@ -75,6 +144,9 @@ class Recorder:
         self._observations: Dict[str, Dict[str, float]] = {}
         self._histograms: Dict[str, List[int]] = {}  # guarded-by: _lock
         self._gauges: Dict[str, float] = {}
+        # race-ok: deque append/popleft are atomic; appended without
+        # the lock by observe_deferred, drained under it
+        self._deferred: collections.deque = collections.deque()
 
     def count(self, name: str, n: int = 1) -> None:
         with self._lock:
@@ -89,19 +161,32 @@ class Recorder:
 
     def observe(self, name: str, value: float) -> None:
         with self._lock:
-            o = self._observations.get(name)
-            if o is None:
-                self._observations[name] = {
-                    "n": 1, "sum": float(value),
-                    "min": float(value), "max": float(value),
-                }
-                self._histograms[name] = [0] * _HIST_BUCKETS
-            else:
-                o["n"] += 1
-                o["sum"] += float(value)
-                o["min"] = min(o["min"], float(value))
-                o["max"] = max(o["max"], float(value))
-            self._histograms[name][_bucket_index(float(value))] += 1
+            self._fold_deferred_locked()
+            self._observe_locked(name, value)
+
+    def observe_deferred(self, name: str, value: float) -> None:
+        self._deferred.append((name, float(value)))
+
+    # requires-lock: _lock
+    def _fold_deferred_locked(self) -> None:
+        while self._deferred:
+            self._observe_locked(*self._deferred.popleft())
+
+    # requires-lock: _lock
+    def _observe_locked(self, name: str, value: float) -> None:
+        o = self._observations.get(name)
+        if o is None:
+            self._observations[name] = {
+                "n": 1, "sum": float(value),
+                "min": float(value), "max": float(value),
+            }
+            self._histograms[name] = [0] * _HIST_BUCKETS
+        else:
+            o["n"] += 1
+            o["sum"] += float(value)
+            o["min"] = min(o["min"], float(value))
+            o["max"] = max(o["max"], float(value))
+        self._histograms[name][_bucket_index(float(value))] += 1
 
     # requires-lock: _lock
     def _percentile_locked(self, name: str, q: float) -> float:
@@ -128,17 +213,13 @@ class Recorder:
         if not 0.0 <= q <= 1.0:
             raise ValueError(f"quantile must be in [0, 1], got {q}")
         with self._lock:
+            self._fold_deferred_locked()
             if name not in self._observations:
                 raise KeyError(f"no observations for {name!r}")
             return self._percentile_locked(name, q)
 
-    @contextmanager
-    def time(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.observe(name, time.perf_counter() - t0)
+    def time(self, name: str) -> Span:
+        return Span(self, name)
 
     def set_gauge(self, name: str, value: float) -> None:
         """Set a last-write-wins instantaneous value (unlike count(),
@@ -164,6 +245,7 @@ class Recorder:
         ``percentile_of_counts``; the cumulative histogram alone would
         let an hour of idle history mask a current latency spike."""
         with self._lock:
+            self._fold_deferred_locked()
             h = self._histograms.get(name)
             return None if h is None else list(h)
 
@@ -177,6 +259,7 @@ class Recorder:
         buckets and feed ``percentile_of_counts``.  64 ints per stream,
         bounded like the histogram itself."""
         with self._lock:
+            self._fold_deferred_locked()
             obs = {
                 name: {**o, "mean": o["sum"] / o["n"],
                        "p50": self._percentile_locked(name, 0.50),
@@ -187,6 +270,41 @@ class Recorder:
             }
             return {"counters": dict(self._counters), "observations": obs,
                     "gauges": dict(self._gauges)}
+
+
+class GcWatch:
+    """A ``gc.callbacks`` hook that times each cyclic collection as the
+    span ``process.gc_s``.  A collection can start inside any code,
+    the Recorder's own locked sections included, so the observation
+    goes through ``observe_deferred``.  ``remove()`` unhooks it."""
+
+    def __init__(self, recorder: Recorder):
+        self._recorder = recorder
+        self._event = None
+        self._t0: Optional[float] = None
+        gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        # collections never overlap: one start/stop pair at a time
+        if phase == "start":
+            self._event = _host_event("process.gc_s")
+            self._event.__enter__()
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            elapsed = time.perf_counter() - self._t0
+            self._t0 = None
+            self._event.__exit__(None, None, None)
+            self._recorder.observe_deferred("process.gc_s", elapsed)
+
+    def remove(self) -> None:
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+
+def watch_gc(recorder: Recorder) -> GcWatch:
+    """Time every cyclic GC collection of this process on ``recorder``
+    until the returned handle's ``remove()``."""
+    return GcWatch(recorder)
 
 
 def percentile_of_counts(hist: Sequence[int], q: float) -> Optional[float]:

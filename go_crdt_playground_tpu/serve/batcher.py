@@ -32,7 +32,14 @@ counters ``serve.ops.acked`` / ``serve.shed.expired`` /
 ``serve.batches`` / ``serve.ack_send_failures``; observations
 ``serve.ingest_latency_s`` (admission→ack, histogram-backed p50/p95/
 p99), ``serve.batch.occupancy`` (live ops per applied batch) and
-``serve.batch.apply_s``; gauge ``serve.queue.depth``.
+``serve.batch.apply_s``; gauge ``serve.queue.depth``.  Spans
+(``Recorder.time``, seconds, also profiler host events):
+``serve.batch.wait_s`` (the loop waiting on the admission queue) and
+``serve.batch.pack_s`` (scheduler reorder and dense row packing); the
+target's own spans cover the apply.  ``serve.batch.apply_s`` is an
+observation, not a span: a span around the whole apply would enclose
+the target's.  While the loop runs, ``obs.watch_gc`` times every GC
+collection of the process as ``process.gc_s``.
 
 Crash-window test hook: ``CRDT_SERVE_CRASH_AFTER_BATCHES=<n>`` SIGKILLs
 the PROCESS right after the n-th batch's WAL fsync returns and BEFORE
@@ -52,6 +59,7 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
+from go_crdt_playground_tpu.obs import timed, watch_gc
 from go_crdt_playground_tpu.serve import protocol
 from go_crdt_playground_tpu.serve.admission import AdmissionQueue, OpRequest
 from go_crdt_playground_tpu.utils.degrade import DegradeWindow
@@ -117,6 +125,8 @@ class MicroBatcher:
         self._stop = threading.Event()
         # race-ok: start()/stop() owner thread only
         self._thread: Optional[threading.Thread] = None
+        # race-ok: start()/stop()/drain() owner thread only
+        self._gc_watch = None
         # race-ok: post-mortem breadcrumb (loop thread writes, a
         # post-stop reader inspects); no control flow depends on it
         self.last_error: Optional[BaseException] = None
@@ -145,6 +155,8 @@ class MicroBatcher:
         if self._thread is not None and self._thread.is_alive():
             raise RuntimeError("batcher already running")
         self._stop.clear()
+        if self.recorder is not None and self._gc_watch is None:
+            self._gc_watch = watch_gc(self.recorder)
         self._thread = threading.Thread(
             target=self._loop,
             name=f"serve-batcher-{getattr(self.target, 'actor', '?')}",
@@ -158,6 +170,7 @@ class MicroBatcher:
         t = self._thread
         if t is not None:
             t.join(timeout=timeout)
+        self._unwatch_gc()
 
     def drain(self, timeout: float = 30.0) -> None:
         """Graceful flush: close the queue to new offers, let the loop
@@ -168,6 +181,7 @@ class MicroBatcher:
         t = self._thread
         if t is None or not t.is_alive():
             self._flush_remaining()
+            self._unwatch_gc()
             return
         deadline = self._clock() + timeout
         while self.queue.depth() > 0 and self._clock() < deadline:
@@ -175,6 +189,12 @@ class MicroBatcher:
         self._stop.set()
         t.join(timeout=max(0.1, deadline - self._clock()))
         self._flush_remaining()
+        self._unwatch_gc()
+
+    def _unwatch_gc(self) -> None:
+        if self._gc_watch is not None:
+            self._gc_watch.remove()
+            self._gc_watch = None
 
     def storage_degraded(self) -> bool:
         """True while the disk-full degrade window is armed: the
@@ -201,9 +221,10 @@ class MicroBatcher:
 
     def _loop(self) -> None:
         while not self._stop.is_set():
-            batch = self.queue.take_batch(
-                max(1, self.width - len(self._carry)),
-                self.idle_wait_s, self.flush_s)
+            with timed(self.recorder, "serve.batch.wait_s"):
+                batch = self.queue.take_batch(
+                    max(1, self.width - len(self._carry)),
+                    self.idle_wait_s, self.flush_s)
             if self.recorder is not None:
                 self.recorder.set_gauge("serve.queue.depth",
                                         self.queue.depth())
@@ -242,29 +263,30 @@ class MicroBatcher:
                 live.append(r)
         if not live:
             return
-        # conflict-aware reorder (serve/scheduler.py): coalesce
-        # same-key runs, spread distinct runs across the target's
-        # ingest stripes, and emit the batch pre-striped.  From here
-        # on `live` IS the durable order — rows pack, counter-prefix,
-        # WAL-log, and ack in the scheduler's emitted order.
-        hint = None
-        if self.scheduler is not None and len(live) > 1:
-            live, assign, self._carry = self.scheduler.schedule(
-                live, self.width)
-            hint = np.full(self.width, -1, np.int32)
-            hint[:len(assign)] = assign
-        # one packed (B, E) pair, B static = the effective width so
-        # every occupancy reuses one compiled program
-        # (ops/ingest.ingest_rows; the striped 2-D program likewise
-        # compiles once per (dp, width/dp) shape)
-        E = self.target.num_elements
-        add_rows = np.zeros((self.width, E), bool)
-        del_rows = np.zeros((self.width, E), bool)
-        live_mask = np.zeros(self.width, bool)
-        for b, r in enumerate(live):
-            rows = add_rows if r.kind == protocol.OP_ADD else del_rows
-            rows[b, r.elements] = True
-            live_mask[b] = True
+        with timed(self.recorder, "serve.batch.pack_s"):
+            # conflict-aware reorder (serve/scheduler.py): coalesce
+            # same-key runs, spread distinct runs across the target's
+            # ingest stripes, and emit the batch pre-striped.  From here
+            # on `live` IS the durable order — rows pack, counter-prefix,
+            # WAL-log, and ack in the scheduler's emitted order.
+            hint = None
+            if self.scheduler is not None and len(live) > 1:
+                live, assign, self._carry = self.scheduler.schedule(
+                    live, self.width)
+                hint = np.full(self.width, -1, np.int32)
+                hint[:len(assign)] = assign
+            # one packed (B, E) pair, B static = the effective width so
+            # every occupancy reuses one compiled program
+            # (ops/ingest.ingest_rows; the striped 2-D program likewise
+            # compiles once per (dp, width/dp) shape)
+            E = self.target.num_elements
+            add_rows = np.zeros((self.width, E), bool)
+            del_rows = np.zeros((self.width, E), bool)
+            live_mask = np.zeros(self.width, bool)
+            for b, r in enumerate(live):
+                rows = add_rows if r.kind == protocol.OP_ADD else del_rows
+                rows[b, r.elements] = True
+                live_mask[b] = True
         t0 = self._clock()
         try:
             # durable on return: state applied + batch δ WAL-fsync'd

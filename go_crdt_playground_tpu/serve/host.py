@@ -163,7 +163,15 @@ class ConnHost:
             self._count(f"{self._prefix}.connections")
             session = Session(conn, peer=f"{addr[0]}:{addr[1]}")
             with self._lock:
-                self._sessions.add(session)
+                # a dial accepted just before stop_accepting: once the
+                # drain has swept the sessions nothing would close it
+                draining = self._draining.is_set()
+                if not draining:
+                    self._sessions.add(session)
+            if draining:
+                session.close()
+                self._conn_slots.release()
+                return
             # finally-shaped slot handoff (the net/peer.py lesson): ANY
             # failure to start the reader must shed the dial AND return
             # the slot, else capacity decays one leak at a time

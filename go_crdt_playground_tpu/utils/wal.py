@@ -39,7 +39,9 @@ on the write path, ``wal.append_errors`` when the disk refuses one,
 partial record that failure may have left behind, ``wal.torn_tail``
 when an open-time repair found a tear, ``wal.truncations`` on
 checkpoint-driven resets; the replay-side ``wal.records`` counter is
-owned by ``net.peer.Node.replay_wal``.
+owned by ``net.peer.Node.replay_wal``.  Span (``Recorder.time``):
+``wal.fsync_s``, one per append — the log's lock, the write, flush and
+fsync of the record.
 """
 
 from __future__ import annotations
@@ -49,6 +51,7 @@ import threading
 import zlib
 from typing import Iterator, List, Optional, Tuple
 
+from go_crdt_playground_tpu.obs import timed
 from go_crdt_playground_tpu.utils import wire
 from go_crdt_playground_tpu.utils.fsutil import fsync_dir as _fsync_dir
 
@@ -253,7 +256,7 @@ class DeltaWal:
         silently drop it, and every later acked record, on restart)."""
         rec = encode_record(body)
         try:
-            with self._lock:
+            with timed(self.recorder, "wal.fsync_s"), self._lock:
                 if self._file is None and not self._dirty:
                     raise ValueError("WAL is closed")
                 try:

@@ -122,3 +122,32 @@ def test_sessions_registry_and_shared_flush_window():
     assert host.sessions() == []
     for c in conns:
         c.close()
+
+
+def test_dial_accepted_as_the_drain_sweeps_is_closed():
+    """A dial accept() returned just before stop_accepting, registered
+    only after close_sessions swept the sessions: nothing else would
+    ever close it, so the accept loop sheds it itself."""
+    host = ConnHost(lambda s, t, b: True, thread_name="test-host")
+    server_end, client_end = socket.socketpair()
+
+    class _Listener:
+        def __init__(self):
+            self.calls = 0
+
+        def accept(self):
+            self.calls += 1
+            if self.calls > 1:
+                raise OSError("listener closed")
+            # the drain runs between accept()'s return and registration
+            host._draining.set()
+            host.close_sessions(0.5)
+            return server_end, ("127.0.0.1", 1)
+
+    host._listener = _Listener()
+    host._accept_loop()
+    client_end.settimeout(5.0)
+    try:
+        assert client_end.recv(1) == b""  # closed, not left open
+    finally:
+        client_end.close()

@@ -418,6 +418,25 @@ def test_m001_undocumented_emission_is_a_warning(tmp_path):
     assert findings[0].symbol == "serve.undocumented.metric"
 
 
+def test_m001_sees_span_names(tmp_path):
+    """Spans are emissions too: ``Recorder.time`` and ``obs.timed``
+    names must be documented like any counter."""
+    pkg = tmp_path / "pkg.py"
+    pkg.write_text(
+        "def f(recorder, timed):\n"
+        "    with recorder.time('serve.phase_s'):\n"
+        "        pass\n"
+        "    with timed(recorder, 'wal.other_s'):\n"
+        "        pass\n"
+        "    recorder.observe_deferred('process.late_s', 1.0)\n")
+    doc = tmp_path / "DESIGN.md"
+    doc.write_text("`serve.phase_s` is documented.\n")
+    findings, _ = metrics_contract.check([str(pkg)], [], [str(doc)])
+    assert sorted(f.symbol for f in findings) == ["process.late_s",
+                                                  "wal.other_s"]
+    assert all(f.severity == "warning" for f in findings)
+
+
 def test_annotation_kinds_do_not_shadow_on_one_statement():
     """Review regression: a guarded-by above a statement plus a
     trailing protocol-ignore on the statement line must BOTH resolve —
