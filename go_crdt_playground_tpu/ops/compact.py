@@ -73,20 +73,24 @@ class CompactDeltaPayload(NamedTuple):
 def _compact_section(mask: jnp.ndarray, idx_dtype, k: int, *values):
     """Pack the lanes where ``mask`` into the first ``count`` of k slots
     (stable, ascending element id).  Returns (idx, valid, packed_values,
-    overflowed)."""
+    overflowed).
+
+    Slot j holds the (j+1)-th set lane: the first lane whose inclusive
+    running count of ``mask`` reaches j + 1, found by a binary search
+    (about log2(E) steps of k gathers).  The work is one E-long running
+    count plus O(k log E) gathers, so it reads the E lanes once rather
+    than once per output lane as a scatter into k slots would."""
     E = mask.shape[-1]
-    pos = jnp.cumsum(mask) - 1                      # destination slot
-    claim = mask & (pos < k)
-    dest = jnp.where(claim, pos, k).astype(jnp.int32)  # k = dropped
-    eids = jnp.arange(E, dtype=idx_dtype)
-    idx = jnp.zeros((k,), idx_dtype).at[dest].set(eids, mode="drop")
-    valid = jnp.zeros((k,), bool).at[dest].set(claim, mode="drop")
-    packed = tuple(
-        jnp.zeros((k,), v.dtype).at[dest].set(
-            jnp.where(claim, v, 0), mode="drop")
-        for v in values
-    )
-    overflowed = jnp.sum(mask) > k
+    csum = jnp.cumsum(mask, dtype=jnp.int32)        # inclusive
+    count = csum[-1]
+    src = jnp.searchsorted(csum, jnp.arange(1, k + 1, dtype=jnp.int32),
+                           side="left")             # E where j >= count
+    valid = jnp.arange(k) < count
+    idx = jnp.where(valid, src, 0).astype(idx_dtype)
+    take = jnp.minimum(src, E - 1)
+    packed = tuple(jnp.where(valid, v[take], jnp.zeros((), v.dtype))
+                   for v in values)
+    overflowed = count > k
     return idx, valid, packed, overflowed
 
 

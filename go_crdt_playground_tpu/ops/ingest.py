@@ -156,8 +156,9 @@ def ingest_rows_delta(state: AWSetDeltaState, add_rows: jnp.ndarray,
     compaction and returns ``compact=None``: the fixed-K form exists to
     shrink the device→host pull, which costs nothing on a CPU backend
     — there the caller compacts host-side from the dense payload
-    (``Node._append_delta_record``), and the scatter-heavy compaction
-    kernel would only slow the batch down.
+    (``Node._append_delta_record``), and the on-device compaction (an
+    E-long running count and k binary searches per section) would only
+    add work to the batch.
     """
     from go_crdt_playground_tpu.ops import compact as compact_ops
     from go_crdt_playground_tpu.ops import delta as delta_ops

@@ -37,6 +37,152 @@ def _payload_fields_equal(a, b):
                               np.asarray(getattr(b, name))), name
 
 
+def _scatter_compact_section(mask, idx_dtype, k, *values):
+    """The earlier form of ``_compact_section``: one E-long scatter into
+    k slots per output lane.  Kept as a second reference."""
+    E = mask.shape[-1]
+    pos = jnp.cumsum(mask) - 1
+    claim = mask & (pos < k)
+    dest = jnp.where(claim, pos, k).astype(jnp.int32)
+    eids = jnp.arange(E, dtype=idx_dtype)
+    idx = jnp.zeros((k,), idx_dtype).at[dest].set(eids, mode="drop")
+    valid = jnp.zeros((k,), bool).at[dest].set(claim, mode="drop")
+    packed = tuple(
+        jnp.zeros((k,), v.dtype).at[dest].set(
+            jnp.where(claim, v, 0), mode="drop")
+        for v in values)
+    return idx, valid, packed, jnp.sum(mask) > k
+
+
+def _numpy_compact_section(mask, idx_dtype, k, *values):
+    """First k set lanes in ascending element id, zeros elsewhere."""
+    lanes = np.flatnonzero(mask)[:k]
+    n = lanes.size
+    idx = np.zeros(k, idx_dtype)
+    idx[:n] = lanes
+    valid = np.arange(k) < n
+    packed = []
+    for v in values:
+        out = np.zeros(k, v.dtype)
+        out[:n] = v[lanes]
+        packed.append(out)
+    return idx, valid, tuple(packed), np.bool_(mask.sum() > k)
+
+
+def _mask_case(E, k, kind, rng):
+    if kind == "empty":
+        return np.zeros(E, bool)
+    if kind == "all":
+        return np.ones(E, bool)
+    if kind in ("exactly_k", "k_plus_1"):
+        n = min(E, k + (kind == "k_plus_1"))
+        m = np.zeros(E, bool)
+        m[rng.choice(E, n, replace=False)] = True
+        return m
+    return rng.random(E) < float(kind)      # a density
+
+
+def _leaves_identical(got, want):
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for i, (g, w) in enumerate(zip(got, want)):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape, (i, g.dtype,
+                                                             w.dtype)
+        assert np.array_equal(g, w), i
+
+
+@pytest.mark.parametrize("E,k,kind", [
+    (1024, 128, "empty"),
+    (1024, 128, "exactly_k"),
+    (1024, 128, "k_plus_1"),
+    (1024, 128, "all"),
+    (1024, 1024, "0.5"),          # K = E
+    (1024, 1024, "all"),
+    (1024, 1, "0.01"),            # K = 1
+    (1024, 1, "empty"),
+    (1000, 128, "0.1"),           # E not a multiple of 128
+    (1000, 128, "k_plus_1"),
+    (1 << 16, 128, "0.0005"),     # random densities at E = 2^16
+    (1 << 16, 128, "0.002"),
+    (1 << 16, 128, "0.01"),
+    (1 << 16, 128, "0.5"),
+    (1 << 16, 4096, "0.05"),
+])
+def test_compact_section_matches_references(E, k, kind):
+    rng = np.random.default_rng(E * 1009 + k)
+    mask = _mask_case(E, k, kind, rng)
+    da = rng.integers(0, 2 ** 32, E, dtype=np.uint32)
+    dc = rng.integers(0, 2 ** 32, E, dtype=np.uint32)
+    got = jax.jit(compact_ops._compact_section, static_argnums=(1, 2))(
+        jnp.asarray(mask), jnp.uint32, k, da, dc)
+    _leaves_identical(got, _numpy_compact_section(mask, np.uint32, k,
+                                                  da, dc))
+    old = jax.jit(_scatter_compact_section, static_argnums=(1, 2))(
+        jnp.asarray(mask), jnp.uint32, k, da, dc)
+    _leaves_identical(got, old)
+
+
+def _dense_payload(rng, E, A, ch_density, del_density):
+    def u32():
+        return rng.integers(0, 2 ** 32, E, dtype=np.uint32)
+
+    return delta_ops.DeltaPayload(
+        src_vv=rng.integers(1, 2 ** 16, A, dtype=np.uint32),
+        changed=rng.random(E) < ch_density, ch_da=u32(), ch_dc=u32(),
+        deleted=rng.random(E) < del_density, del_da=u32(), del_dc=u32(),
+        src_actor=np.uint32(3),
+        src_processed=rng.integers(1, 2 ** 16, A, dtype=np.uint32))
+
+
+def test_compact_payload_batch_matches_per_row_references():
+    """Eight vmapped rows, some overflowing one section, some neither:
+    every field equals the per-row NumPy and scatter references, and an
+    overflowed row zeroes its clock lanes."""
+    rng = np.random.default_rng(83)
+    E, A, kc, kd = 4096, 16, 64, 32
+    rows = [_dense_payload(rng, E, A, d, d / 2)
+            for d in (0.0, 0.001, 0.005, 0.01, 0.02, 0.05, 0.3, 1.0)]
+    batch = jax.tree.map(lambda *xs: np.stack(xs), *rows)
+    got = compact_ops.compact_payload_batch(batch, kc, kd)
+    over = np.asarray(got.overflow)
+    assert over.any() and not over.all()
+    for r, p in enumerate(rows):
+        row = jax.tree.map(lambda x: np.asarray(x)[r], got)
+        for ref in (_numpy_compact_section, _scatter_compact_section):
+            ch = ref(p.changed, np.uint32, kc, p.ch_da, p.ch_dc)
+            dl = ref(p.deleted, np.uint32, kd, p.del_da, p.del_dc)
+            _leaves_identical(
+                (row.ch_idx, row.ch_valid, (row.ch_da, row.ch_dc)),
+                ch[:3])
+            _leaves_identical(
+                (row.del_idx, row.del_valid, (row.del_da, row.del_dc)),
+                dl[:3])
+            assert bool(row.overflow) == bool(ch[3] | dl[3])
+        keep = not bool(row.overflow)
+        for name in ("src_vv", "src_processed"):
+            want = getattr(p, name) if keep else np.zeros(A, np.uint32)
+            assert np.array_equal(getattr(row, name), want), (r, name)
+
+
+def test_compact_payload_lowers_without_scatter_at_served_size():
+    """At the served store's size (E = 2^20, K = 128) the compaction is
+    gathers over a running count: an E-long scatter per output lane
+    would read all 2^20 lanes to fill 128 slots."""
+    E, A = 1 << 20, 16
+    sds = jax.ShapeDtypeStruct
+    p = delta_ops.DeltaPayload(
+        src_vv=sds((A,), jnp.uint32), changed=sds((E,), jnp.bool_),
+        ch_da=sds((E,), jnp.uint32), ch_dc=sds((E,), jnp.uint32),
+        deleted=sds((E,), jnp.bool_), del_da=sds((E,), jnp.uint32),
+        del_dc=sds((E,), jnp.uint32), src_actor=sds((), jnp.uint32),
+        src_processed=sds((A,), jnp.uint32))
+    hlo = jax.jit(compact_ops.compact_payload,
+                  static_argnums=(1, 2)).lower(p, 128, 128).as_text()
+    assert "gather" in hlo
+    assert "scatter" not in hlo
+
+
 def test_compact_expand_roundtrip_when_fits():
     rng = random.Random(43)
     st = _random_delta_state(rng)
