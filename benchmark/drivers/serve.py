@@ -11,12 +11,18 @@ members and clock through QUERY, the peak memory, a crash-shaped stop
 (no final checkpoint) and a restart from the durable directory, whose
 members and clock must match too.  The reference replays the acked
 ops from the seed (``reference/served.py``).
+
+Every run, traced or not, also puts the window as the host saw it into
+the line's ``info`` (``_host_info``), which no metric or check reads:
+so the runs of a set can be told apart by their phases.
 """
 
 from __future__ import annotations
 
 import json
+import math
 import os
+import resource
 import shutil
 import subprocess
 import sys
@@ -25,7 +31,7 @@ import time
 
 import numpy as np
 
-from benchmark import gen, harness
+from benchmark import gen, harness, readers
 from benchmark.loadgen import ACKED, FAILED, NO_REPLY
 from benchmark.reference import served as ref
 
@@ -142,6 +148,7 @@ def run(ctx: dict) -> None:
             harness.start_trace(trace_dir)
         ctx["compiles"].start()
         rec0 = rec.snapshot()
+        use0 = resource.getrusage(resource.RUSAGE_SELF)
         t0 = time.monotonic()
         ctx["setup_s"] = t0 - ctx["t_start"]
         proc.stdin.write(f"GO {t0!r}\n")
@@ -149,6 +156,7 @@ def run(ctx: dict) -> None:
         with jax.profiler.TraceAnnotation("bench.window"):
             time.sleep(max(0.0, t0 + seconds - time.monotonic()))
         rec1 = rec.snapshot()
+        use1 = resource.getrusage(resource.RUSAGE_SELF)
         t1 = time.monotonic()
         compiles = ctx["compiles"].stop()
         if ctx["trace"]:
@@ -204,7 +212,8 @@ def run(ctx: dict) -> None:
                        "check_s": time.monotonic() - t_done,
                        "regime": None if regime is None else
                        f"{getattr(regime[0], '__name__', '?')}:k={regime[1]}",
-                       "members": int(members.size)}
+                       "members": int(members.size),
+                       **_host_info(ctx, p, keys, use0, use1)}
         if ctx["trace"]:
             from benchmark import trace_reduce
 
@@ -217,6 +226,34 @@ def run(ctx: dict) -> None:
         if fe is not None:
             _crash_stop(fe)
         shutil.rmtree(tmp, ignore_errors=True)
+
+
+def _host_info(ctx, p, keys, use0, use1) -> dict:
+    """The window as the host saw it: each span's time per batch and
+    the window's counts (``readers.phase_split``), how late the load
+    generator sent (open loop: after the op was due; closed: after the
+    reply that freed its slot), acks in each second of the window, and
+    the CPU time of the server and of the load generator."""
+    ops = ctx["ops"]
+    if p["loop"] == "closed":
+        late = readers.slot_late_ms(ctx, gen.split(keys, int(
+            p["connections"])), int(p.get("in_flight", 8)))
+    else:
+        late = readers.due_late_ms(ctx)
+    late = late.tolist()
+    at = ops["acked_at"][~np.isnan(ops["acked_at"])] - ctx["t0"]
+    acks = np.histogram(at, bins=np.arange(math.ceil(ctx["seconds"]) + 1))
+
+    def cpu_s(use):
+        return use.ru_utime + use.ru_stime
+
+    return {"phases": readers.phase_split(ctx),
+            "gen_late_ms": {"p50": harness.quantile(late, 0.5),
+                            "p99": harness.quantile(late, 0.99)},
+            "acked_by_second": acks[0].tolist(),
+            "host": {"cpus": len(os.sched_getaffinity(0)),
+                     "server_cpu_s": cpu_s(use1) - cpu_s(use0),
+                     "loadgen_cpu_s": float(ops["cpu_s"])}}
 
 
 def _flag(flags, name: str, default):

@@ -1,12 +1,7 @@
-"""overflow_share.closed: per cent of batches whose on-device fixed-K delta compaction overflowed, counter ingest.compact_overflow (layer: replica node apply)."""
+"""overflow_share.closed: per cent of batches whose on-device fixed-K delta compaction overflowed, counter ingest.compact_overflow (layer: replica node apply); closed loop."""
 
-from benchmark.readers import _counter
+from benchmark import readers
 
 
 def value(ctx):
-    if "ingest.compact_overflow" not in ctx["rec1"]["counters"]:
-        return None  # a program that does not count it
-    batches = _counter(ctx, "serve.batches")
-    if not batches:
-        return None
-    return 100.0 * _counter(ctx, "ingest.compact_overflow") / batches
+    return readers.overflow_share(ctx)

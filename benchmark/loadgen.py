@@ -16,14 +16,16 @@ stdin/stdout:
                     writing per-op times and outcomes to the output path
 
 Per op it records when it was due, when it was sent, when its reply
-came and what the reply was; the harness turns those into the cell's
-numbers and checks the acked ops against the reference.
+came and what the reply was, and its own CPU time over the window;
+the harness turns those into the cell's numbers and checks the acked
+ops against the reference.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import resource
 import sys
 import threading
 import time
@@ -96,6 +98,10 @@ def _resolve(conns, n: int, deadline: float):
     return acked_at, outcome
 
 
+def _cpu_s(use) -> float:
+    return use.ru_utime + use.ru_stime
+
+
 def _backend_started() -> bool:
     """Did anything in this process start a JAX backend?"""
     if "jax" not in sys.modules:
@@ -139,6 +145,7 @@ def run(job: dict, stdin, stdout) -> None:
         raise SystemExit(f"expected GO, got {line!r}")
     t0 = float(line[1])
     t_end = t0 + seconds
+    use0 = resource.getrusage(resource.RUSAGE_SELF)
     due = np.full(n, np.nan)
     if p["loop"] == "open":
         due[warm:] = gen.arrivals(p, seed, seconds)
@@ -153,13 +160,15 @@ def run(job: dict, stdin, stdout) -> None:
         t.start()
     for t in threads:
         t.join()
+    use1 = resource.getrusage(resource.RUSAGE_SELF)
     acked_at, outcome = _resolve(conns, n, t_end + _WAIT_PAST_CLOSE_S)
     outcome[:warm] = ACKED
     for c in conns:
         c.client.close()
     np.savez(job["out"], sent=sent, due=due, acked_at=acked_at,
              outcome=outcome, t0=t0, t_end=t_end, warm=warm,
-             backend_started=_backend_started())
+             backend_started=_backend_started(),
+             cpu_s=_cpu_s(use1) - _cpu_s(use0))
     print("DONE", flush=True, file=stdout)
 
 

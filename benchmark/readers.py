@@ -40,15 +40,39 @@ def ack_quantile_ms(ctx, q: float):
     return v * 1e3
 
 
-def late_quantile_ms(ctx, q: float):
-    """Quantile of send time minus due time: how late the generator
-    sent (open loop)."""
+def due_late_ms(ctx):
+    """Open loop: per op sent in the window, send time minus due time
+    in ms; None in a closed loop (no op is due)."""
     ops, win = _window(ctx)
     due = ops["due"][win]
     if np.all(np.isnan(due)):
         return None
     late = ops["sent"][win] - (ctx["t0"] + due)
-    return harness.quantile(late[~np.isnan(late)].tolist(), q) * 1e3
+    return late[~np.isnan(late)] * 1e3
+
+
+def late_quantile_ms(ctx, q: float):
+    """Quantile of send time minus due time: how late the generator
+    sent (open loop)."""
+    late = due_late_ms(ctx)
+    return None if late is None else harness.quantile(late.tolist(), q)
+
+
+def slot_late_ms(ctx, conn_of: np.ndarray, in_flight: int) -> np.ndarray:
+    """Closed loop: per op sent in the window, send time minus the reply
+    that freed its slot, in ms.  A connection sends its ops in stream
+    order, the first ``in_flight`` at once, and its m-th later one when
+    its m-th reply of the window (in reply order) frees a slot."""
+    ops, win = _window(ctx)
+    sent, acked_at = ops["sent"][win], ops["acked_at"][win]
+    late = []
+    for c in np.unique(conn_of[win]):
+        mine = np.nonzero((conn_of[win] == c) & ~np.isnan(sent))[0]
+        freed = np.sort(acked_at[mine])
+        m = mine.size - in_flight
+        if m > 0:
+            late.append(sent[mine[in_flight:]] - freed[:m])
+    return np.concatenate(late) * 1e3 if late else np.zeros(0)
 
 
 def _obs(ctx, name):
@@ -77,14 +101,63 @@ def batch_rows(ctx):
     return s / n if n else None
 
 
-def outside_apply_ms(ctx):
-    """Window time per batch not spent in the durable apply: packing
-    the dense rows, deadline checks, acks, waiting for the batch."""
+def pull_ms(ctx):
+    """The batch delta's pull and record build per batch: the compact
+    pull (``wal.delta.pull_s``) plus the dense record
+    (``wal.delta.dense_s``) where the compact form overflowed."""
+    n_compact, s_compact = _obs(ctx, "wal.delta.pull_s")
+    n_dense, s_dense = _obs(ctx, "wal.delta.dense_s")
     batches = _counter(ctx, "serve.batches")
-    a = apply_ms(ctx)
-    if not batches or a is None:
+    if not (n_compact or n_dense) or not batches:
         return None
-    return (ctx["t1"] - ctx["t0"]) * 1e3 / batches - a
+    return (s_compact + s_dense) / batches * 1e3
+
+
+def overflow_share(ctx):
+    """Per cent of batches whose on-device fixed-K delta compaction
+    overflowed (counter ``ingest.compact_overflow``), over the batches
+    whose compact form was pulled (``wal.delta.pull_s``): the program
+    counts an overflow just as that pull's span ends, so a window's
+    edge cannot split the two (``serve.batches``, counted after the
+    acks, can: a share over 100).  0 where no compact form exists (no
+    on-device compaction, as on the CPU)."""
+    if "ingest.compact_overflow" not in ctx["rec1"]["counters"]:
+        return None  # a program that does not count it
+    if not _counter(ctx, "serve.batches"):
+        return None
+    pulled, _ = _obs(ctx, "wal.delta.pull_s")
+    over = _counter(ctx, "ingest.compact_overflow")
+    return 100.0 * over / pulled if pulled else 0.0
+
+
+# the served path's phases as the program spans them: the batcher's
+# loop (wait, pack, then the durable apply: dispatch, the compact pull
+# or the dense record, fsync), the checkpoint on the supervisor thread
+# and the process's GC pauses
+PHASES = {"wait": "serve.batch.wait_s", "pack": "serve.batch.pack_s",
+          "apply": "serve.batch.apply_s",
+          "dispatch": "node.ingest.dispatch_s",
+          "pull": "wal.delta.pull_s", "dense": "wal.delta.dense_s",
+          "fsync": "wal.fsync_s", "checkpoint": "node.checkpoint_s",
+          "gc": "process.gc_s"}
+
+
+def phase_split(ctx):
+    """Each phase's window time per batch in ms, the batch cycle, and
+    what of the cycle no span of the loop covers (the acks and the
+    loop's own work); with the window's counts.  None without
+    batches."""
+    batches = _counter(ctx, "serve.batches")
+    if not batches:
+        return None
+    ms = {k: _obs(ctx, name)[1] * 1e3 / batches
+          for k, name in PHASES.items()}
+    ms["cycle"] = (ctx["t1"] - ctx["t0"]) * 1e3 / batches
+    ms["unspanned"] = ms["cycle"] - ms["wait"] - ms["pack"] - ms["apply"]
+    return {"batches": batches,
+            "compact_overflow": _counter(ctx, "ingest.compact_overflow"),
+            "checkpoints": _obs(ctx, "node.checkpoint_s")[0],
+            "ms_per_batch": ms}
 
 
 def idle_share(ctx):

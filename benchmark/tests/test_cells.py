@@ -61,8 +61,8 @@ def test_a_cell_from_new_files_only(tmp_path):
                tmp_path / "go_crdt_playground_tpu")
     bench = json.loads(json.dumps(BENCH))
     mix = harness.load_json(os.path.join(harness.BENCH, "traffic",
-                                         "churn.closed.json"))
-    mix.update(connections=2, in_flight=4, warmup_ops=8,
+                                         "churn.k80.json"))
+    mix.update(connections=2, warmup_ops=8,
                store={"preload": "full", "history_ops": 500})
     with open(tmp_path / "benchmark" / "traffic" / "churn.two.json",
               "w") as f:
@@ -71,7 +71,7 @@ def test_a_cell_from_new_files_only(tmp_path):
         {"name": "serve-1m.churn.two", "config": "awset-serve-1m",
          "traffic": "churn.two", "chips": 1, "why": "two connections"})
     for m in bench["end_to_end"] + bench["per_layer"]:
-        if "workloads" in m and "serve-1m.churn.closed" in m["workloads"]:
+        if "workloads" in m and "serve-1m.churn.k80" in m["workloads"]:
             m["workloads"].append("serve-1m.churn.two")
     with open(tmp_path / "BENCHMARK.json", "w") as f:
         json.dump(bench, f)
@@ -86,4 +86,4 @@ def test_a_cell_from_new_files_only(tmp_path):
                        env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, r.stderr[-3000:]
     line = json.loads(r.stdout.strip().splitlines()[-1])
-    assert line["correct"] and "acked_ops_per_s" in line["metrics"]
+    assert line["correct"] and "ack_p50_ms" in line["metrics"]
