@@ -4,7 +4,9 @@ diverged δ-AWSet fleets through the program's convergence driver,
 delta_semantics=...)``.
 
 Convergence i of a run starts from the fleet ``fleetgen.fleet(seed,
-i)``, made on the device in one jitted call.  Set-up builds and
+i)``, made on the device in one jitted call: on the default device
+here, laid out by the caller's ``sharding`` where another driver runs
+this one (``run(ctx, sharding)``).  Set-up builds and
 converges one fleet outside the window's index range, which compiles
 every program the window runs.  The window counts convergences until
 ``--seconds`` have passed; the one in flight then finishes and counts.
@@ -26,6 +28,8 @@ import numpy as np
 from benchmark import fleetgen, gen, harness
 from benchmark.reference import fleet as ref
 
+FAMILY = "fleet"
+
 _WARMUP_INDEX = 1 << 30       # a fleet index the window never reaches
 _MAX_SAMPLE_INDEX = 32
 
@@ -35,9 +39,10 @@ def _sizes(cfg):
             int(cfg["writers"]))
 
 
-def builder(cfg: dict):
+def builder(cfg: dict, sharding=None):
     """``build(seed, i) -> AWSetDeltaState`` on the device, one jitted
-    call per fleet (seed and index ride as data)."""
+    call per fleet (seed and index ride as data); every field laid out
+    by ``sharding`` where one is given."""
     import jax
     import jax.numpy as jnp
 
@@ -45,10 +50,12 @@ def builder(cfg: dict):
 
     R, E, A, W = _sizes(cfg)
 
-    @jax.jit
-    def build(seed_lo, seed_hi, i):
+    def fields(seed_lo, seed_hi, i):
         return AWSetDeltaState(**fleetgen.fleet(
             jnp, (seed_lo, seed_hi, i), R, E, A, W))
+
+    build = (jax.jit(fields) if sharding is None
+             else jax.jit(fields, out_shardings=sharding))
 
     def make(seed: int, i: int):
         return build(jnp.uint32(seed & 0xFFFFFFFF),
@@ -87,13 +94,13 @@ def converge(cfg: dict, state, control: bool = False):
         return -1, state
 
 
-def run(ctx: dict) -> None:
+def run(ctx: dict, sharding=None) -> None:
     import jax
 
     cfg, p = ctx["config"], ctx["traffic"]
     seed, seconds = ctx["seed"], ctx["seconds"]
     R, E, A, W = _sizes(cfg)
-    make = builder(cfg)
+    make = builder(cfg, sharding)
     t_jax = time.monotonic() - ctx["t_start"]
     rounds0, final = converge(cfg, make(seed, _WARMUP_INDEX),
                               ctx.get("control", False))

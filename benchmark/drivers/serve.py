@@ -35,6 +35,8 @@ from benchmark import gen, harness, readers
 from benchmark.loadgen import ACKED, FAILED, NO_REPLY
 from benchmark.reference import served as ref
 
+FAMILY = "served"
+
 
 def _write_store(path: str, E: int, A: int, present, dot, deleted,
                  del_dot, clock: int) -> None:

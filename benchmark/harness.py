@@ -5,7 +5,11 @@ in a file of its own, found by the name ``BENCHMARK.json`` gives it:
 
 * ``configs/<config>.json`` (the ``file`` of the configuration) names
   its ``driver``: ``drivers/<driver>.py`` builds the system, warms it
-  up, runs the measured window and checks the outputs;
+  up, runs the measured window and checks the outputs, and declares
+  its ``FAMILY`` (``served`` or ``fleet``), the faults its cells are
+  tested against; the file's ``rehearsal`` holds the sizes a CPU
+  rehearsal overrides (``benchmark/tests``), which a run on the chip
+  never reads;
 * ``traffic/<traffic>.json``: the mix's parameters, read by
   ``gen.py`` (served) or the driver (fleet);
 * ``e2e/<metric>.py`` and ``layers/<metric>.py``: each metric's reader,
@@ -13,7 +17,8 @@ in a file of its own, found by the name ``BENCHMARK.json`` gives it:
 * ``peaks.json``: the chip's peaks by ``device_kind``.
 
 A reader that finds nothing to read returns None and the metric is left
-out of the line.
+out of the line.  A driver finds the cell's devices, the first
+``chips`` that JAX lists, in ``ctx["devices"]``.
 """
 
 from __future__ import annotations
@@ -86,12 +91,11 @@ def device_info(chips: int, allow_cpu: bool) -> dict:
 
     devs = jax.devices()
     d = devs[0]
-    if not allow_cpu and (d.platform != "tpu" or len(devs) < chips):
+    if len(devs) < chips or (not allow_cpu and d.platform != "tpu"):
         raise SystemExit(
             f"no accelerator for this cell: JAX sees {len(devs)} "
             f"{d.platform} device(s), the cell asks for {chips} TPU chip(s)")
-    return {"platform": d.platform, "kind": d.device_kind,
-            "count": min(len(devs), chips)}
+    return {"platform": d.platform, "kind": d.device_kind, "count": chips}
 
 
 def memory_peak(count: int) -> int:
@@ -149,7 +153,8 @@ def parse(argv):
 
 def run(argv, t_start: float, allow_cpu: bool = False,
         sizes: dict = None, stdout=None, control: bool = False) -> dict:
-    """One run of one cell.  ``allow_cpu`` and ``sizes`` (overrides of
+    """One run of one cell.  ``allow_cpu`` (any platform, still with
+    as many devices as the cell has chips) and ``sizes`` (overrides of
     the configuration's sizes) are for the CPU rehearsals only;
     ``control`` runs the cell's control (``control.py``)."""
     args = parse(argv)
@@ -164,10 +169,13 @@ def run(argv, t_start: float, allow_cpu: bool = False,
     place_cache()
     device = device_info(int(cell["chips"]), allow_cpu)
     peaks = load_json(os.path.join(BENCH, "peaks.json"))
+    import jax
+
     ctx = {"cell": cell, "config": cfg, "traffic": traffic,
            "seed": args.seed, "seconds": args.seconds,
            "trace": bool(args.trace), "t_start": t_start,
-           "device": device, "compiles": CompileCounter(),
+           "device": device, "devices": jax.devices()[:device["count"]],
+           "compiles": CompileCounter(),
            "control": control,
            "peaks": peaks["devices"].get(device["kind"])}
     if ctx["peaks"] is None and not allow_cpu:

@@ -9,7 +9,7 @@ import os
 
 import numpy as np
 
-from benchmark import harness
+from benchmark import harness, trace_reduce
 from benchmark.loadgen import ACKED
 
 
@@ -184,3 +184,37 @@ def roofline(ctx, model: str):
     if seconds <= 0:
         return None
     return 100.0 * total_bytes / seconds / ctx["peaks"]["hbm_bytes_per_s"]
+
+
+def collective_s(ctx):
+    """Seconds of the traced window in which a collective ran (the
+    exchange between chips), per device: the union of each device's
+    collective intervals (``trace_reduce.collective_intervals``),
+    averaged over the devices.  None without a device trace."""
+    red = ctx.get("trace_red")
+    if not red or not red["collectives"]:
+        return None
+    per = [trace_reduce.covered_s(iv) for iv in red["collectives"]]
+    return sum(per) / len(per)
+
+
+def ici_share(ctx, model: str):
+    """Per cent of the chips' interconnect bandwidth the exchange
+    reached over the window: the bytes the collectives must move
+    (``roofline/<model>.py``'s ``traced_ici_bytes``, from the shapes,
+    summed over the devices) over the collective seconds summed over
+    the devices, against one chip's ``ici_bytes_per_s``.  None where
+    no collective ran or no bytes are due."""
+    red = ctx.get("trace_red")
+    peak = (ctx.get("peaks") or {}).get("ici_bytes_per_s")
+    if not red or not peak:
+        return None
+    seconds = sum(trace_reduce.covered_s(iv) for iv in red["collectives"])
+    if seconds <= 0:
+        return None
+    mod = harness.load_module(os.path.join(harness.BENCH, "roofline",
+                                           model + ".py"))
+    total_bytes = mod.traced_ici_bytes(red, ctx["shapes"])
+    if total_bytes <= 0:
+        return None
+    return 100.0 * total_bytes / seconds / peak

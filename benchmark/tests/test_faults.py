@@ -1,13 +1,19 @@
 """A run whose timed path is broken underneath must come out not
 correct: once per fault a cell can have.  The harness's look for a chip
-is skipped (CPU, tiny sizes); everything else is a whole run.
+is skipped (CPU, tiny sizes, as many devices as the cell has chips);
+everything else is a whole run.  A cell's faults follow its driver's
+``FAMILY``, not the driver's name, so a cell whose new driver declares
+a family is tested with no edit here.
 
 Served cells: a batch apply that leaves the state unchanged, half of
 every batch left out, an answer altered where it is produced (QUERY).
-Fleet cell: a ring round that leaves the fleet unchanged, half of the
-rows left out of every round, an answer altered where it is produced
-(the converged fleet).  One chip each: no exchange between chips to
-leave out.  And each cell's control (``control.py``)."""
+Fleet cells, each planted in the program's convergence entry
+``parallel/gossip.rounds_to_convergence`` (so every fleet driver that
+converges through it is covered): a convergence that returns its fleet
+unchanged, half of the rows left out of it, an answer altered where it
+is produced (the converged fleet), and on more than one chip the
+exchange between chips left out (each chip's rows converged alone).
+And each cell's control (``control.py``)."""
 
 import os
 
@@ -18,13 +24,22 @@ from benchmark import harness
 from conftest import run_cell
 
 _BENCH = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
-_DRIVER = {c["name"]: harness.load_json(os.path.join(harness.ROOT,
-                                                     c["file"]))["driver"]
-           for c in _BENCH["configs"]}
+
+
+def _family(config: dict) -> str:
+    driver = harness.load_json(os.path.join(harness.ROOT,
+                                            config["file"]))["driver"]
+    return harness.load_module(os.path.join(
+        harness.BENCH, "drivers", driver + ".py")).FAMILY
+
+
+_FAMILY = {c["name"]: _family(c) for c in _BENCH["configs"]}
 SERVED = [w["name"] for w in _BENCH["workloads"]
-          if _DRIVER[w["config"]] == "serve"]
+          if _FAMILY[w["config"]] == "served"]
 FLEET = [w["name"] for w in _BENCH["workloads"]
-         if _DRIVER[w["config"]] == "fleet"]
+         if _FAMILY[w["config"]] == "fleet"]
+FLEET_MULTICHIP = [w["name"] for w in _BENCH["workloads"]
+                   if _FAMILY[w["config"]] == "fleet" and w["chips"] > 1]
 
 
 def _node():
@@ -71,55 +86,73 @@ def test_served_fault_is_not_correct(cell, fault, monkeypatch):
     assert not line["correct"], line["checks"]
 
 
-def _gossip():
+def _wrap_convergence(mp, fault):
+    """Plant ``fault(state, rounds, final, orig, *a, **kw) -> (rounds,
+    final)`` in the program's convergence entry."""
     from go_crdt_playground_tpu.parallel import gossip
 
-    gossip._advance_program.cache_clear()
-    return gossip
+    orig = gossip.rounds_to_convergence
+
+    def broken(state, *a, **kw):
+        rounds, final = orig(state, *a, **kw)
+        return fault(state, rounds, final, orig, *a, **kw)
+
+    mp.setattr(gossip, "rounds_to_convergence", broken)
 
 
 def _fleet_unchanged(mp):
-    g = _gossip()
-    mp.setattr(g, "delta_ring_gossip_round",
-               lambda state, offset, drop_mask=None, **kw: state)
+    _wrap_convergence(mp, lambda state, rounds, final, *_a, **_kw:
+                      (rounds, state))
 
 
 def _fleet_half_rows(mp):
     import jax
 
-    g = _gossip()
-    orig = g.delta_ring_gossip_round
-
-    def half(state, offset, drop_mask=None, **kw):
-        merged = orig(state, offset, drop_mask, **kw)
+    def half(state, rounds, final, *_a, **_kw):
         h = state.vv.shape[0] // 2
-        return jax.tree.map(lambda m, s: m.at[h:].set(s[h:]), merged,
-                            state)
+        return rounds, jax.tree.map(lambda m, s: m.at[h:].set(s[h:]),
+                                    final, state)
 
-    mp.setattr(g, "delta_ring_gossip_round", half)
+    _wrap_convergence(mp, half)
 
 
 def _fleet_altered_answer(mp):
-    g = _gossip()
-    orig = g.rounds_to_convergence
-
-    def altered(state, *a, **kw):
-        rounds, final = orig(state, *a, **kw)
+    def altered(state, rounds, final, *_a, **_kw):
         return rounds, final._replace(
             present=final.present.at[0, 0].set(~final.present[0, 0]))
 
-    mp.setattr(g, "rounds_to_convergence", altered)
+    _wrap_convergence(mp, altered)
 
 
-@pytest.mark.parametrize("fault", [_fleet_unchanged, _fleet_half_rows,
-                                   _fleet_altered_answer])
-@pytest.mark.parametrize("cell", FLEET)
+def _fleet_no_exchange(mp):
+    """Each chip's block of rows converged alone: nothing crosses
+    between chips."""
+    import jax
+    import jax.numpy as jnp
+
+    def alone(state, rounds, final, orig, *a, **kw):
+        n = len(state.vv.sharding.device_set)
+        b = state.vv.shape[0] // n
+        parts = [orig(jax.tree.map(lambda x: x[i * b:(i + 1) * b], state),
+                      *a, **kw) for i in range(n)]
+        return (max(r for r, _ in parts),
+                jax.tree.map(lambda *xs: jnp.concatenate(xs),
+                             *[f for _, f in parts]))
+
+    _wrap_convergence(mp, alone)
+
+
+FLEET_FAULTS = [
+    pytest.param(cell, fault, id=f"{cell}-{fault.__name__}")
+    for cell in FLEET
+    for fault in (_fleet_unchanged, _fleet_half_rows, _fleet_altered_answer)
+    + ((_fleet_no_exchange,) if cell in FLEET_MULTICHIP else ())]
+
+
+@pytest.mark.parametrize("cell,fault", FLEET_FAULTS)
 def test_fleet_fault_is_not_correct(cell, fault, monkeypatch):
     fault(monkeypatch)
-    try:
-        line = run_cell(cell, seconds=0.5)
-    finally:
-        _gossip()
+    line = run_cell(cell, seconds=0.5)
     assert not line["correct"], line["checks"]
 
 

@@ -37,3 +37,24 @@ def test_program_converges_to_the_reference(control):
         assert mm["members"] > 0
     else:
         assert mm == {"members": 0, "vv": 0}
+
+
+@pytest.mark.parametrize("rows", [1024, 4096])
+def test_row_sharded_fleet_is_bitwise_the_unsharded(rows):
+    """The same builder with its fleet laid out row by row over four
+    devices gives the same fleet, bit for bit."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    cfg = {**CFG, "replicas": rows}
+    mesh = Mesh(np.array(jax.devices()[:4]), ("rows",))
+    seed, i = 2**40 + 7, 3
+    sharded = driver.builder(cfg, NamedSharding(
+        mesh, PartitionSpec("rows")))(seed, i)
+    plain = driver.builder(cfg)(seed, i)
+    for name in plain._fields:
+        s = getattr(sharded, name)
+        assert len(s.sharding.device_set) == 4, name
+        assert s.sharding.shard_shape(s.shape)[0] == rows // 4, name
+        assert np.array_equal(np.asarray(s),
+                              np.asarray(getattr(plain, name))), name

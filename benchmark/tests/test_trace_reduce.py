@@ -3,6 +3,8 @@
 δ-fleet convergence at 1024 x 256 inside a ``bench.window`` span), and
 on a CPU trace, which has no device plane."""
 
+import hashlib
+import json
 import os
 
 import pytest
@@ -10,6 +12,10 @@ import pytest
 from benchmark import harness, trace_reduce
 
 DATA = os.path.join(os.path.dirname(__file__), "data", "small.xplane.pb")
+# sha256 of ``json.dumps(reduce(DATA), sort_keys=True)`` before the
+# reduction kept collectives: every key it had then reads the same
+_BEFORE_COLLECTIVES = (
+    "a11053d23cff3a4d8b3c06c420902636ea1dc2146e957c02d6aed80cadc9e320")
 
 
 def _model(name):
@@ -28,6 +34,15 @@ def test_chip_trace_reduces():
     # idle gaps and busy time tile the window
     assert sum(t for _, t in bd["idle_gaps"]) <= \
         red["window_s"] - red["busy_s"] + 1e-9
+
+
+def test_existing_keys_read_the_same_with_collectives_kept():
+    red = trace_reduce.reduce(DATA)
+    old = {k: v for k, v in red.items() if k != "collectives"}
+    assert hashlib.sha256(json.dumps(old, sort_keys=True).encode()) \
+        .hexdigest() == _BEFORE_COLLECTIVES
+    # one chip, no exchange
+    assert red["collectives"] == [[]]
 
 
 def test_chip_trace_names_both_kernels():

@@ -8,7 +8,9 @@ is the host span the harness opens around its measured window
 union of the intervals of device operations (events on the lines that
 name operations, e.g. ``XLA Ops``, on the ``/device:`` planes), averaged
 over the devices; an operation's time is the sum of its events'
-durations inside the window.
+durations inside the window.  Each device's collectives (the exchange
+between chips) are kept as intervals of their own
+(``collective_intervals``).
 """
 
 from __future__ import annotations
@@ -16,12 +18,17 @@ from __future__ import annotations
 import collections
 import glob
 import os
+import re
 from typing import Dict, List, Optional, Tuple
 
 WINDOW_SPAN = "bench.window"
 # device lines that carry one event per executed operation; module and
 # step lines overlap them and would count the same time twice
 _OP_LINES = ("XLA Ops",)
+# the HLO collectives: synchronous as themselves, asynchronous as an
+# ``<op>-start`` / ``<op>-done`` pair
+COLLECTIVES = ("collective-permute", "all-reduce", "all-gather",
+               "all-to-all", "reduce-scatter")
 
 
 def find_xplane(log_dir: str) -> str:
@@ -79,12 +86,16 @@ def reduce(path: str, window_span: str = WINDOW_SPAN,
     op_time: Dict[str, float] = collections.defaultdict(float)
     op_count: Dict[str, int] = collections.defaultdict(int)
     busy_per_device = []
+    collectives = []
     gaps: List[Tuple[float, float]] = []
     for plane in device_planes:
         lines = [ln for ln in plane.lines if ln.name in _OP_LINES]
         if not lines:
             continue
         intervals = []
+        events = [ev for line in lines for ev in _events(line)]
+        collectives.append([((s - lo) * 1e-9, (e - lo) * 1e-9) for s, e
+                            in collective_intervals(events, lo, hi)])
         for line in lines:
             for name, s, e in _events(line):
                 c = _clip(s, e, lo, hi)
@@ -115,9 +126,62 @@ def reduce(path: str, window_span: str = WINDOW_SPAN,
         "devices": len(busy_per_device),
         "op_s": dict(op_time),
         "op_count": dict(op_count),
+        "collectives": collectives,
         "breakdown": {"device_ops": [[n, t] for n, t in ops[:top]],
                       "idle_gaps": idle},
     }
+
+
+def covered_s(intervals) -> float:
+    """The length of the union of ``(start, end)`` intervals."""
+    return sum(e - s for s, e in _union(list(intervals)))
+
+
+def _collective(name: str):
+    """``(collective, "sync" | "start" | "done")`` of an ``XLA Ops``
+    event, or None for any other operation."""
+    op = opcode(name)
+    for c in COLLECTIVES:
+        if op == c:
+            return c, "sync"
+        if op in (c + "-start", c + "-done"):
+            return c, op[len(c) + 1:]
+    return None
+
+
+def collective_intervals(events, lo: float, hi: float):
+    """One device's collectives as ``(start, end)`` intervals clipped to
+    the window ``[lo, hi]``, from its operations' ``(name, start, end)``
+    events in any order.  A synchronous collective is its own event.  An
+    asynchronous pair spans from the start of its ``-start`` event to
+    the end of the ``-done`` event that names it as its operand.  A
+    ``-start`` whose ``-done`` the trace does not hold runs to the
+    window's end; a ``-done`` whose ``-start`` it does not hold began
+    before the window.
+    """
+    out = []
+    pending = {}                         # open -start: instruction -> start
+    for name, s, e in sorted(events, key=lambda ev: ev[1]):
+        kind = _collective(name)
+        if kind is None:
+            continue
+        c, phase = kind
+        instr, _, rest = name.partition(" = ")
+        if phase == "start":
+            pending[instr.strip()] = s
+            continue
+        if phase == "done":             # its one operand: the -start
+            operand = re.search(r"%[\w.\-]+",
+                                rest.partition(f"{c}-done(")[2])
+            s = pending.pop(operand.group(0), lo) if operand else lo
+        iv = _clip(s, e, lo, hi)
+        if iv is not None:
+            out.append(iv)
+    for s in pending.values():
+        iv = _clip(s, hi, lo, hi)
+        if iv is not None:
+            out.append(iv)
+    return sorted(out)
 
 
 def _label(gap, host_lines, window_span: str) -> str:
